@@ -1,0 +1,10 @@
+"""Share (%) of the traced window in which no op ran on the device:
+1 - busy / window, busy being the union of the device's op intervals,
+averaged over the chips."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
