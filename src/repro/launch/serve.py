@@ -162,15 +162,16 @@ def main(argv=None) -> int:
                          "with benchmarks/autotune.py instead")
     ap.add_argument("--trace-out", default=None, metavar="trace.json",
                     help="export the engine's structured trace as Chrome-"
-                         "trace JSON (open at https://ui.perfetto.dev)")
+                         "trace JSON (open at https://ui.perfetto.dev); with "
+                         "--jax-profile its times are on the profile's clock")
     ap.add_argument("--metrics-every", type=int, default=0, metavar="N",
                     help="print a one-line metrics summary every N engine "
                          "steps (active slots, pool occupancy, tok/s, "
                          "accept rate)")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the run into DIR "
-                         "(TensorBoard/Perfetto; engine closure dispatches "
-                         "are TraceAnnotation'd)")
+                         "(TensorBoard/Perfetto; the engine's phase spans "
+                         "and closure dispatches are TraceAnnotation'd)")
     ap.add_argument("--probe-overlap", action="store_true",
                     help="after the run, measure decode overlap efficiency "
                          "per collective schedule (sequential vs batch-split "
@@ -303,9 +304,13 @@ def main(argv=None) -> int:
         jaxprof.start(args.jax_profile)
     outs = _drive(eng, args)
     wall = time.perf_counter() - t0
+    anchor = None
     if args.jax_profile:
         from repro.obs import jaxprof
         jaxprof.stop()
+        # with --trace-out too: put the ring's events on the profile's clock
+        # so the two files overlay (docs/observability.md)
+        anchor = jaxprof.clock_anchor(args.jax_profile)
 
     total_new = sum(len(v) for v in outs.values())
     if args.disagg:
@@ -332,7 +337,7 @@ def main(argv=None) -> int:
         if args.trace_out:
             from repro.obs import write_chrome_trace
             ev = eng.prefill.trace.events() + eng.decode.trace.events()
-            n = write_chrome_trace(ev, args.trace_out)
+            n = write_chrome_trace(ev, args.trace_out, anchor=anchor)
             print(f"trace: {n} events -> {args.trace_out} (both engines)")
         for rid in sorted(outs)[:3]:
             print(f"  rid {rid}: {outs[rid][:10]}"
@@ -383,9 +388,12 @@ def main(argv=None) -> int:
             print(f"  schedule {name:<12} {t * 1e3:.2f} ms/step")
     if args.trace_out:
         from repro.obs import write_chrome_trace
-        n = write_chrome_trace(eng.trace.events(), args.trace_out)
+        n = write_chrome_trace(eng.trace.events(), args.trace_out,
+                               anchor=anchor)
         print(f"trace: {n} events -> {args.trace_out} "
-              f"(dropped={eng.trace.dropped}; open at https://ui.perfetto.dev)")
+              f"(dropped={eng.trace.dropped}; "
+              f"{'on the profile clock; ' if anchor else ''}"
+              f"open at https://ui.perfetto.dev)")
     for rid in sorted(outs)[:3]:
         print(f"  rid {rid}: {outs[rid][:10]}{'...' if len(outs[rid]) > 10 else ''}")
     return 0

@@ -7,7 +7,9 @@ The measurement spine of the serving stack (docs/observability.md):
     idiom while percentiles/peaks come from real distributions;
   * ``trace``     — a bounded ring of structured ``TraceEvent``s emitted by
     the scheduler (grant/pack/defer), allocator (alloc/free/cow/adopt) and
-    engine phase loops (prefill/decode calls, spec verify, preemption);
+    engine phase loops (prefill/decode calls, spec verify, preemption), and
+    ``TraceRing.span``, the engine's phase spans (ring event, profiler
+    annotation and self-time counter under one name);
   * ``export``    — Chrome-trace/Perfetto JSON from the ring (plus schema
     validation used by the CI trace-schema lane);
   * ``replay``    — recompute counters from a trace stream; the conservation

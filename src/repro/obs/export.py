@@ -14,7 +14,7 @@ non-negative timestamps in non-decreasing order.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.obs.trace import TraceEvent
 
@@ -35,10 +35,21 @@ def _tid(ev: TraceEvent) -> int:
 
 
 def chrome_trace(events: Sequence[TraceEvent],
-                 process_name: str = "repro-serving") -> Dict[str, Any]:
+                 process_name: str = "repro-serving",
+                 anchor: Optional[Tuple[float, float]] = None
+                 ) -> Dict[str, Any]:
     """Trace Event Format document.  Event times are rebased to the stream's
-    first timestamp so the trace starts at t=0."""
-    t0 = min((ev.ts for ev in events), default=0.0)
+    first timestamp so the trace starts at t=0 — or, given ``anchor``
+    (``jaxprof.clock_anchor``: one instant as a perf_counter stamp and as
+    profiler microseconds), put on the profiler's clock, so the document
+    overlays the ``jax.profiler`` trace of the same run; events from before
+    the profile started are left out."""
+    if anchor is None:
+        t0 = min((ev.ts for ev in events), default=0.0)
+        base_us = 0.0
+    else:
+        t0, base_us = anchor
+        events = [ev for ev in events if ev.ts >= t0]
     out: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": _PID, "tid": 0,
          "args": {"name": process_name}},
@@ -54,7 +65,7 @@ def chrome_trace(events: Sequence[TraceEvent],
         out.append({"name": "thread_name", "ph": "M", "pid": _PID,
                     "tid": 10 + s, "args": {"name": f"slot {s}"}})
     for ev in sorted(events, key=lambda e: e.ts):
-        ts_us = (ev.ts - t0) * 1e6
+        ts_us = base_us + (ev.ts - t0) * 1e6
         args: Dict[str, Any] = dict(ev.payload)
         if ev.rid >= 0:
             args["rid"] = ev.rid
@@ -75,10 +86,11 @@ def chrome_trace(events: Sequence[TraceEvent],
 
 
 def write_chrome_trace(events: Sequence[TraceEvent], path: str,
-                       process_name: str = "repro-serving") -> int:
+                       process_name: str = "repro-serving",
+                       anchor: Optional[Tuple[float, float]] = None) -> int:
     """Write the JSON document; returns the number of trace events written
     (metadata records excluded)."""
-    doc = chrome_trace(events, process_name=process_name)
+    doc = chrome_trace(events, process_name=process_name, anchor=anchor)
     with open(path, "w") as f:
         json.dump(doc, f)
     return sum(1 for e in doc["traceEvents"] if e["ph"] != "M")

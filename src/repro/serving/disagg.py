@@ -57,6 +57,7 @@ class RequestRecord:
     last_token: int                   # next decode input (not yet in KV)
     draft_table: Optional[Dict[int, int]]   # speculative self-draft state —
     draft_last: int                         # without it, spec streams diverge
+    t_first_grant: float = -1.0       # queue-wait stamp, as t_submit
 
 
 @dataclass

@@ -54,7 +54,6 @@ from repro.core.chunking import grant_buckets, round_to_bucket
 from repro.core.overlap import AxisCtx
 from repro.layers import embeddings as emb_lib
 from repro.models import api
-from repro.obs import jaxprof
 from repro.obs.registry import (ACCEPT_LEN_BUCKETS, GRANT_SIZE_BUCKETS,
                                 MetricsRegistry, TPOT_BUCKETS_S,
                                 TTFT_BUCKETS_S)
@@ -70,6 +69,13 @@ from repro.serving.scheduler import TokenBudgetScheduler, plan_chunks
 
 # TP serving shards each page pool's KV-head axis: (Pd, page, HEADS, ps, hd)
 KV_POOL_SPEC = P(None, None, "model", None, None)
+
+# host time of each step phase: the self time of its span (TraceRing.span),
+# seconds, cumulative.  With prefill_s + decode_s (which hold the dispatch
+# spans and the device_wait fences) they add up to the engine/step spans.
+HOST_COUNTERS = ("host_step_other_s", "host_admit_s", "host_schedule_s",
+                 "host_pages_s", "host_inputs_s", "host_logits_s",
+                 "host_sample_s", "host_release_s")
 
 
 class PagedEngine:
@@ -248,8 +254,11 @@ class PagedEngine:
             "cow_copies", "peak_used_pages", "prefill_pad_tokens",
             "prefill_samples", "spec_calls", "spec_tokens", "prefill_grants",
             "resumed_grants", "prefill_pad_rows", "migrations",
-            "migrated_pages", "migration_us"))
+            "migrated_pages", "migration_us") + HOST_COUNTERS)
         self.metrics = self.registry.view()
+        self._host = {n: self.registry.counter(n) for n in HOST_COUNTERS}
+        self._dispatch = {n: self.registry.counter(n + "_dispatch_s")
+                          for n in ("prefill", "decode")}
 
     # ------------------------------------------------------------------
     # request lifecycle
@@ -282,15 +291,18 @@ class PagedEngine:
 
     def _admit(self) -> None:
         free = [i for i, s in enumerate(self.slots) if s is None]
-        while free and self.scheduler.waiting:
-            rid = self.scheduler.pop_waiting()
-            st = self._by_rid[rid]
-            st.slot = free.pop(0)
-            st.prefilled = 0
-            self.slots[st.slot] = st
-            self.lengths[st.slot] = 0
-            self.trace.emit("admit", rid=rid, slot=st.slot)
-            self._try_share_prefix(st)
+        if not (free and self.scheduler.waiting):
+            return
+        with self.trace.span("engine/admit", self._host["host_admit_s"]):
+            while free and self.scheduler.waiting:
+                rid = self.scheduler.pop_waiting()
+                st = self._by_rid[rid]
+                st.slot = free.pop(0)
+                st.prefilled = 0
+                self.slots[st.slot] = st
+                self.lengths[st.slot] = 0
+                self.trace.emit("admit", rid=rid, slot=st.slot)
+                self._try_share_prefix(st)
 
     def _try_share_prefix(self, st: RequestState) -> None:
         """Map a live donor's matching prompt-prefix pages into this request
@@ -349,12 +361,15 @@ class PagedEngine:
         """Free rid's pages and invalidate their position entries: attention
         validity is derived from ``pos >= 0``, so a reused page that is only
         partially overwritten must not expose the dead request's tail KV."""
-        pages = self.alloc.free(rid)
-        if pages:
-            new_kv = dict(self.kv.arrays)
-            new_kv["pos"] = new_kv["pos"].at[
-                jnp.asarray(pages, jnp.int32)].set(-1)
-            self.kv.arrays = new_kv
+        with self.trace.span("engine/release",
+                             self._host["host_release_s"]) as sp:
+            pages = self.alloc.free(rid)
+            sp.payload["n_pages"] = len(pages)
+            if pages:
+                new_kv = dict(self.kv.arrays)
+                new_kv["pos"] = new_kv["pos"].at[
+                    jnp.asarray(pages, jnp.int32)].set(-1)
+                self.kv.arrays = new_kv
 
     def _preempt_one(self, protect: List[int]) -> bool:
         """Evict one running request (recompute mode).  False if none left."""
@@ -763,6 +778,25 @@ class PagedEngine:
             return n_tokens
         return round_to_bucket(n_tokens, self._buckets)
 
+    def _device_call(self, name: str, phase: str, call):
+        """Run one jitted engine call: ``call()`` dispatches under the span
+        ``name`` (its time into ``<phase>_dispatch_s``), then every output
+        is fenced under ``engine/device_wait``.  Returns the outputs, the
+        dispatch start and the seconds from it to the fence — the fenced
+        time ``prefill_s``/``decode_s`` sum.  Dispatch returns before the
+        device finishes, and the KV scatter can outlive the logits, so the
+        fence must cover EVERY output."""
+        with self.trace.span(name, self._dispatch[phase]) as d, \
+                self._mesh_ctx():
+            out = call()
+        with self.trace.span("engine/device_wait") as w:
+            jax.block_until_ready(out)
+        return out, d.t0, w.t1 - d.t0
+
+    def _logits_to_host(self, logits) -> np.ndarray:
+        with self.trace.span("engine/logits", self._host["host_logits_s"]):
+            return np.asarray(jax.device_get(logits))
+
     def _run_grant(self, st: RequestState, start: int, n_tokens: int,
                    padded: int, last: bool) -> Optional[int]:
         """Execute one prefill grant; returns the sampled token if ``last``.
@@ -771,35 +805,31 @@ class PagedEngine:
         pad tail is zero tokens, masked out of attention and KV scatter."""
         req = st.request
         slot = st.slot
-        n_patches = self._eff_extra(req) if start == 0 else 0
-        toks_all = self._resident_tokens(st)
-        # text tokens covered by this grant (patches occupy the first
-        # ``eff_extra`` effective positions of the first grant)
-        t0 = max(0, start - self._eff_extra(req)) if req.patches is not None \
-            else start
-        n_text = n_tokens - n_patches
-        buf = np.zeros(padded - n_patches, np.int32)
-        buf[:n_text] = toks_all[t0:t0 + n_text]
-        tokens = jnp.asarray(buf[None])
-        patches = jnp.asarray(req.patches[None]) if n_patches else None
+        with self.trace.span("engine/inputs", self._host["host_inputs_s"]):
+            n_patches = self._eff_extra(req) if start == 0 else 0
+            toks_all = self._resident_tokens(st)
+            # text tokens covered by this grant (patches occupy the first
+            # ``eff_extra`` effective positions of the first grant)
+            t0 = max(0, start - self._eff_extra(req)) \
+                if req.patches is not None else start
+            n_text = n_tokens - n_patches
+            buf = np.zeros(padded - n_patches, np.int32)
+            buf[:n_text] = toks_all[t0:t0 + n_text]
+            tokens = jnp.asarray(buf[None])
+            patches = jnp.asarray(req.patches[None]) if n_patches else None
 
-        bt_row = jnp.asarray(self.alloc.block_table(req.rid,
-                                                    self.max_blocks)[None])
-        states_slot = jax.tree_util.tree_map(
-            lambda a: a[:, slot:slot + 1], self.states)
-        fn = self._get_prefill(padded - n_patches, n_patches,
-                               resumed=start > 0)
-        t0_wall = time.perf_counter()
-        with self._mesh_ctx(), jaxprof.annotate(f"prefill/T={padded}"):
-            logits_last, new_kv, new_states = fn(
+            bt_row = jnp.asarray(self.alloc.block_table(req.rid,
+                                                        self.max_blocks)[None])
+            states_slot = jax.tree_util.tree_map(
+                lambda a: a[:, slot:slot + 1], self.states)
+            fn = self._get_prefill(padded - n_patches, n_patches,
+                                   resumed=start > 0)
+        (logits_last, new_kv, new_states), t0_wall, dur = self._device_call(
+            f"prefill/T={padded}", "prefill", lambda: fn(
                 self.params, tokens, patches, self.kv.arrays, states_slot,
-                bt_row, jnp.int32(start), jnp.int32(n_tokens))
-        # dispatch returns before the device finishes; the timed region must
-        # cover EVERY output or prefill_s under-reports (the KV scatter can
-        # outlive the logits) — dispatch-only time keeps its own counter
-        self.metrics["prefill_dispatch_s"] += time.perf_counter() - t0_wall
-        jax.block_until_ready((logits_last, new_kv, new_states))
-        dur = time.perf_counter() - t0_wall
+                bt_row, jnp.int32(start), jnp.int32(n_tokens)))
+        if st.t_first_grant < 0:
+            st.t_first_grant = t0_wall
         self.metrics["prefill_s"] += dur
         self.metrics["prefill_tokens"] += n_tokens
         self.metrics["prefill_pad_tokens"] += padded - n_tokens
@@ -812,9 +842,10 @@ class PagedEngine:
         self.states = jax.tree_util.tree_map(
             lambda big, new: big.at[:, slot:slot + 1].set(new.astype(big.dtype)),
             self.states, new_states)
-        return self._commit_grant_row(
-            st, start, n_tokens,
-            np.asarray(jax.device_get(logits_last))[0] if last else None, last)
+        logits_row = self._logits_to_host(logits_last)[0] if last else None
+        with self.trace.span("engine/sample", self._host["host_sample_s"]):
+            return self._commit_grant_row(st, start, n_tokens, logits_row,
+                                          last)
 
     def _commit_grant_row(self, st: RequestState, start: int, n_tokens: int,
                           logits_row, last: bool) -> Optional[int]:
@@ -873,26 +904,27 @@ class PagedEngine:
         R = len(group)
         rows = round_to_bucket(R, self._row_buckets)
         T = padded
-        toks = np.zeros((rows, T), np.int32)
-        starts = np.zeros(rows, np.int32)
-        n_reals = np.zeros(rows, np.int32)
-        bts = np.full((rows, self.max_blocks), -1, np.int32)
-        for r, (st, start, n, _, _last) in enumerate(group):
-            toks_all = self._resident_tokens(st)
-            toks[r, :n] = toks_all[start:start + n]
-            starts[r] = start
-            n_reals[r] = n
-            bts[r] = self.alloc.block_table(st.request.rid, self.max_blocks)
-        fn = self._get_prefill_batched(T, rows,
-                                       all_fresh=bool(np.all(starts == 0)))
-        t0_wall = time.perf_counter()
-        with self._mesh_ctx(), jaxprof.annotate(f"prefill/T={T}x{rows}"):
-            logits_last, new_kv = fn(self.params, jnp.asarray(toks),
-                                     self.kv.arrays, jnp.asarray(bts),
-                                     jnp.asarray(starts), jnp.asarray(n_reals))
-        self.metrics["prefill_dispatch_s"] += time.perf_counter() - t0_wall
-        jax.block_until_ready((logits_last, new_kv))
-        dur = time.perf_counter() - t0_wall
+        with self.trace.span("engine/inputs", self._host["host_inputs_s"]):
+            toks = np.zeros((rows, T), np.int32)
+            starts = np.zeros(rows, np.int32)
+            n_reals = np.zeros(rows, np.int32)
+            bts = np.full((rows, self.max_blocks), -1, np.int32)
+            for r, (st, start, n, _, _last) in enumerate(group):
+                toks_all = self._resident_tokens(st)
+                toks[r, :n] = toks_all[start:start + n]
+                starts[r] = start
+                n_reals[r] = n
+                bts[r] = self.alloc.block_table(st.request.rid,
+                                                self.max_blocks)
+            fn = self._get_prefill_batched(
+                T, rows, all_fresh=bool(np.all(starts == 0)))
+        (logits_last, new_kv), t0_wall, dur = self._device_call(
+            f"prefill/T={T}x{rows}", "prefill", lambda: fn(
+                self.params, jnp.asarray(toks), self.kv.arrays,
+                jnp.asarray(bts), jnp.asarray(starts), jnp.asarray(n_reals)))
+        for st, *_ in group:
+            if st.t_first_grant < 0:
+                st.t_first_grant = t0_wall
         n_total = int(n_reals.sum())
         self.metrics["prefill_s"] += dur
         self.metrics["prefill_tokens"] += n_total
@@ -904,14 +936,15 @@ class PagedEngine:
         self.kv.arrays = new_kv
         logits_np = None
         if any(p[4] for p in group):
-            logits_np = np.asarray(jax.device_get(logits_last))
-        for r, (st, start, n, _, last) in enumerate(group):
-            tok = self._commit_grant_row(
-                st, start, n, logits_np[r] if last else None, last)
-            if tok is not None:
-                events.append((st.request.rid, tok))
-                if st.done:
-                    self._finish(st)
+            logits_np = self._logits_to_host(logits_last)
+        with self.trace.span("engine/sample", self._host["host_sample_s"]):
+            for r, (st, start, n, _, last) in enumerate(group):
+                tok = self._commit_grant_row(
+                    st, start, n, logits_np[r] if last else None, last)
+                if tok is not None:
+                    events.append((st.request.rid, tok))
+                    if st.done:
+                        self._finish(st)
 
     def _finish(self, st: RequestState) -> None:
         # decode_tokens is tallied where tokens are produced (_decode_phase),
@@ -941,21 +974,23 @@ class PagedEngine:
         if st is None or st.slot < 0:
             return None                       # preempted by an earlier grant
         start, end = g.start, g.start + g.n_tokens
-        if start == 0 and st.prefilled == 0:
-            # retry prefix sharing: a donor granted EARLIER this step (batch-1
-            # mode: already ran; packed mode: earlier pack) has committed its
-            # first chunks by now
-            self._try_share_prefix(st)
-            start = st.prefilled
-            if end <= start:                  # grant fully covered by sharing
-                return None
-        if not self._ensure_pages(g.rid, end) or \
-                not self._cow_range(g.rid, start, end):
-            # unreachable once add_request validated pool capacity; a
-            # silent skip here would spin run_until_complete forever
-            raise RuntimeError(
-                f"page pool too small for request {g.rid}'s prefill chunk "
-                f"even after evicting; increase ServingConfig.num_pages")
+        with self.trace.span("engine/pages", self._host["host_pages_s"]):
+            if start == 0 and st.prefilled == 0:
+                # retry prefix sharing: a donor granted EARLIER this step
+                # (batch-1 mode: already ran; packed mode: earlier pack) has
+                # committed its first chunks by now
+                self._try_share_prefix(st)
+                start = st.prefilled
+                if end <= start:              # grant fully covered by sharing
+                    return None
+            if not self._ensure_pages(g.rid, end) or \
+                    not self._cow_range(g.rid, start, end):
+                # unreachable once add_request validated pool capacity; a
+                # silent skip here would spin run_until_complete forever
+                raise RuntimeError(
+                    f"page pool too small for request {g.rid}'s prefill "
+                    f"chunk even after evicting; increase "
+                    f"ServingConfig.num_pages")
         # the scheduler owns grant rounding (g.padded); re-round only
         # when same-step prefix sharing shrank the grant, and never pad
         # patch-carrying grants (the scheduler is model-agnostic)
@@ -974,7 +1009,15 @@ class PagedEngine:
         pending = [(s.request.rid, s.prefilled, s.chunk_plan)
                    for s in self.slots
                    if s is not None and s.prefilled < sum(s.chunk_plan)]
-        grants = self.scheduler.grant_prefill(pending)
+        if not pending:
+            return
+        with self.trace.span("engine/schedule",
+                             self._host["host_schedule_s"]) as sp:
+            grants = self.scheduler.grant_prefill(pending)
+            if self._batch_prefill:
+                packs = self.scheduler.pack_grants(grants,
+                                                   max_rows=self.max_batch)
+            sp.payload["grants"] = [g.n_tokens for g in grants]
         if not self._batch_prefill:
             for g in grants:
                 prep = self._prep_grant(g)
@@ -998,8 +1041,7 @@ class PagedEngine:
         # sharing adopts only COMMITTED tokens, and packmates commit together
         # after the call — running donor and sharee in one call would
         # silently lose the share that the sequential path gets.
-        for pack in self.scheduler.pack_grants(grants,
-                                               max_rows=self.max_batch):
+        for pack in packs:
             ready, deferred = [], []
             for g in pack:
                 if self._defer_for_packmate_sharing(g, ready):
@@ -1027,15 +1069,18 @@ class PagedEngine:
         st = self._by_rid.get(g.rid)
         if st is None or st.slot < 0 or st.prefilled > 0 or g.start != 0:
             return False
-        prompt = np.asarray(st.request.prompt, np.int32)
-        if len(prompt) < self.ps:
-            return False                  # sharing needs a full page match
-        head = prompt[:self.ps]
-        for p_st, _, _, _, _ in prepped:
-            donor = np.asarray(p_st.request.prompt, np.int32)
-            if len(donor) >= self.ps and np.array_equal(donor[:self.ps], head):
-                return True
-        return False
+        with self.trace.span("engine/schedule",
+                             self._host["host_schedule_s"]):
+            prompt = np.asarray(st.request.prompt, np.int32)
+            if len(prompt) < self.ps:
+                return False              # sharing needs a full page match
+            head = prompt[:self.ps]
+            for p_st, _, _, _, _ in prepped:
+                donor = np.asarray(p_st.request.prompt, np.int32)
+                if len(donor) >= self.ps and \
+                        np.array_equal(donor[:self.ps], head):
+                    return True
+            return False
 
     def _run_groups(self, ready: List[Tuple],
                     events: List[Tuple[int, int]]) -> None:
@@ -1100,61 +1145,59 @@ class PagedEngine:
         active = [s for s in active if s.slot >= 0]
         if not active:
             return
-        K = self._spec_window(active)
-        # grow every decoder's capacity by the window width (may evict; an
-        # evicted request drops out of `active` below — filtered by slot, not
-        # list.remove, whose __eq__ scan would compare prompt arrays)
-        for st in active:
-            if st.slot < 0:
-                continue
-            L = int(self.lengths[st.slot])
-            if not self._ensure_pages(st.request.rid, L + K) or \
-                    not self._cow_range(st.request.rid, L, L + K):
-                raise RuntimeError(
-                    f"page pool too small for a {K}-token decode step; "
-                    f"increase ServingConfig.num_pages")
+        with self.trace.span("engine/pages", self._host["host_pages_s"]):
+            K = self._spec_window(active)
+            # grow every decoder's capacity by the window width (may evict;
+            # an evicted request drops out of `active` below — filtered by
+            # slot, not list.remove, whose __eq__ scan would compare prompt
+            # arrays)
+            for st in active:
+                if st.slot < 0:
+                    continue
+                L = int(self.lengths[st.slot])
+                if not self._ensure_pages(st.request.rid, L + K) or \
+                        not self._cow_range(st.request.rid, L, L + K):
+                    raise RuntimeError(
+                        f"page pool too small for a {K}-token decode step; "
+                        f"increase ServingConfig.num_pages")
         active = [s for s in active if s.slot >= 0]
         if not active:
             return
-        B = self.max_batch
-        mask = np.zeros(B, bool)
-        for st in active:
-            mask[st.slot] = True
-        bt = np.stack([self.alloc.block_table(s.request.rid, self.max_blocks)
-                       if s is not None and mask[i] else
-                       np.full(self.max_blocks, -1, np.int32)
-                       for i, s in enumerate(self.slots)])
-        toks = np.zeros((B, K), np.int32)
-        toks[:, 0] = self.last_tokens.astype(np.int32)
-        drafts: Dict[int, List[int]] = {}
-        if K > 1:
+        with self.trace.span("engine/inputs", self._host["host_inputs_s"]):
+            B = self.max_batch
+            mask = np.zeros(B, bool)
             for st in active:
-                i = st.slot
-                drafts[i] = self._drafts[i].draft(self.spec_k)
-                toks[i, 1:] = drafts[i]
-        lens = jnp.asarray(self.lengths.astype(np.int32))
-        S = self._kv_splits(K)
-        if self._decode_schedule == "batch_split" and len(active) < 2:
-            # a single resident request has no second batch half to overlap
-            # with — run the sequential closure for this step instead of a
-            # batch-split call whose other half is pure scratch work
-            decode_fn = self._get_fallback_decode(K, S)
-            self.trace.emit("decision", point="decode_schedule",
-                            fallback=1, active=len(active), k=int(K))
-        else:
-            decode_fn = self._get_decode(K, S)
-        t0 = time.perf_counter()
-        with self._mesh_ctx(), jaxprof.annotate(f"decode/K={K}/S={S}"):
-            logits, new_kv, new_states = decode_fn(
+                mask[st.slot] = True
+            bt = np.stack([self.alloc.block_table(s.request.rid,
+                                                  self.max_blocks)
+                           if s is not None and mask[i] else
+                           np.full(self.max_blocks, -1, np.int32)
+                           for i, s in enumerate(self.slots)])
+            toks = np.zeros((B, K), np.int32)
+            toks[:, 0] = self.last_tokens.astype(np.int32)
+            drafts: Dict[int, List[int]] = {}
+            if K > 1:
+                for st in active:
+                    i = st.slot
+                    drafts[i] = self._drafts[i].draft(self.spec_k)
+                    toks[i, 1:] = drafts[i]
+            lens = jnp.asarray(self.lengths.astype(np.int32))
+            S = self._kv_splits(K)
+            if self._decode_schedule == "batch_split" and len(active) < 2:
+                # a single resident request has no second batch half to
+                # overlap with — run the sequential closure for this step
+                # instead of a batch-split call whose other half is pure
+                # scratch work
+                decode_fn = self._get_fallback_decode(K, S)
+                self.trace.emit("decision", point="decode_schedule",
+                                fallback=1, active=len(active), k=int(K))
+            else:
+                decode_fn = self._get_decode(K, S)
+        (logits, new_kv, new_states), t0, dur = self._device_call(
+            f"decode/K={K}/S={S}", "decode", lambda: decode_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(bt), lens,
-                self.kv.arrays, self.states, jnp.asarray(mask))
-        # fence EVERY output inside the timed region: the logits transfer
-        # below would otherwise hide the KV-scatter tail and decode_s would
-        # report dispatch time (the async view keeps its own counter)
-        self.metrics["decode_dispatch_s"] += time.perf_counter() - t0
-        jax.block_until_ready((logits, new_kv, new_states))
-        dur = time.perf_counter() - t0
-        logits = np.asarray(jax.device_get(logits))
+                self.kv.arrays, self.states, jnp.asarray(mask)))
+        logits = self._logits_to_host(logits)
         self.metrics["decode_s"] += dur
         self.metrics["decode_calls"] += 1
         self.trace.emit("decode_call", dur=dur, ts=t0, k=K, active=len(active))
@@ -1163,6 +1206,14 @@ class PagedEngine:
         self.kv.arrays = new_kv
         self.states = new_states
 
+        with self.trace.span("engine/sample", self._host["host_sample_s"]):
+            self._accept_decoded(active, K, logits, drafts, dur, events)
+
+    def _accept_decoded(self, active: List[RequestState], K: int,
+                        logits: np.ndarray, drafts: Dict[int, List[int]],
+                        dur: float, events: List[Tuple[int, int]]) -> None:
+        """Sample (K=1) or accept (speculative verify) each active row's
+        tokens from the decode call's host ``logits`` and commit them."""
         rollback: List[Tuple[int, int]] = []      # (page, offset) to unmap
         for st in active:
             i = st.slot
@@ -1215,22 +1266,23 @@ class PagedEngine:
     def step(self) -> List[Tuple[int, int]]:
         """One engine iteration: admission -> budgeted prefill chunks ->
         batched decode.  Returns (rid, token) events."""
-        events: List[Tuple[int, int]] = []
-        self.metrics["steps"] += 1
-        if self.scheduler.runs_prefill:
-            self._admit()
-            self._prefill_phase(events)
-        if self.scheduler.runs_decode:
-            self._decode_phase(events)
-        used = self.alloc.used_pages
-        frag = self.alloc.fragmentation()
-        self.registry.gauge("pool_occupancy").set(used)
-        self.registry.gauge("free_list_fragmentation").set(frag)
-        self.metrics["peak_used_pages"] = max(self.metrics["peak_used_pages"],
-                                              used)
-        self.trace.emit("pool", used=used, free=self.alloc.free_pages,
-                        frag=frag)
-        return events
+        with self.trace.span("engine/step", self._host["host_step_other_s"]):
+            events: List[Tuple[int, int]] = []
+            self.metrics["steps"] += 1
+            if self.scheduler.runs_prefill:
+                self._admit()
+                self._prefill_phase(events)
+            if self.scheduler.runs_decode:
+                self._decode_phase(events)
+            used = self.alloc.used_pages
+            frag = self.alloc.fragmentation()
+            self.registry.gauge("pool_occupancy").set(used)
+            self.registry.gauge("free_list_fragmentation").set(frag)
+            self.metrics["peak_used_pages"] = max(
+                self.metrics["peak_used_pages"], used)
+            self.trace.emit("pool", used=used, free=self.alloc.free_pages,
+                            frag=frag)
+            return events
 
     def run_until_complete(self, max_steps: int = 10_000) -> Dict[int, List[int]]:
         out: Dict[int, List[int]] = {}
@@ -1275,7 +1327,8 @@ class PagedEngine:
                 chunk_plan=tuple(st.chunk_plan), t_submit=st.t_submit,
                 t_first=st.t_first, last_token=int(self.last_tokens[slot]),
                 draft_table=dict(d.table) if d is not None else None,
-                draft_last=d.last if d is not None else -1))
+                draft_last=d.last if d is not None else -1,
+                t_first_grant=st.t_first_grant))
             self.trace.emit("detach", rid=rid, slot=slot)
             self._release_pages(rid)
             if self.prefix_cache is not None:
@@ -1322,6 +1375,7 @@ class PagedEngine:
             st.prefilled = rec.prefilled
             st.chunk_plan = tuple(rec.chunk_plan)
             st.t_first = rec.t_first
+            st.t_first_grant = rec.t_first_grant
             self.slots[slot] = st
             self._by_rid[rid] = st
             # committed tokens came over in the blob's lengths; the first

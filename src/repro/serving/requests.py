@@ -41,6 +41,10 @@ class RequestState:
     prefilled: int = 0                # prompt tokens already resident in pages
     chunk_plan: Tuple[int, ...] = ()  # ISO chunk boundaries = scheduling quanta
     t_submit: float = 0.0
+    # start of the first prefill call carrying any of the request's tokens;
+    # set once, kept across preemption: t_first_grant - t_submit is the
+    # queue wait, t_first - t_first_grant the prefill chunks up to the token
+    t_first_grant: float = -1.0
     t_first: float = -1.0             # wall time of the first sampled token
 
     @property
