@@ -459,11 +459,41 @@ def window_page_coords(lengths, block_tables, k_tokens: int, page_size: int,
 def scatter_pages(pages: jnp.ndarray, page, off, kv) -> jnp.ndarray:
     """Write token KV into a head-major pool at (page, offset) coordinates.
 
-    pages: (Pd, N, Hkv, ps, hd); page/off: int arrays of any common shape
-    ``I``; kv: (Pd, *I, Hkv, hd) token-major values.  Returns the updated
-    pool (duplicate coordinates keep one writer, as ``.at[].set`` does)."""
-    vals = jnp.moveaxis(kv, 0, page.ndim)           # (*I, Pd, Hkv, hd)
-    return pages.at[:, page, :, off].set(vals.astype(pages.dtype))
+    pages: (Pd, N+1, Hkv, ps, hd), page N the scratch page; page/off: int
+    (*R, T), each row a run of T tokens at consecutive positions (a decode
+    window or a prefill grant) whose pad and inactive tokens are routed to
+    the scratch page; kv: (Pd, *R, T, Hkv, hd) token-major values.  Returns
+    the updated pool, equal to a token-by-token write on every live page.
+
+    Layout rule: the update window must be contiguous in the head-major
+    layout, or XLA relayouts the whole pool around the write (a pool-sized
+    copy each way, every call).  A token's ``(Hkv, hd)`` values are not
+    contiguous there, since ``ps`` lies between them; a page's
+    ``(Hkv, ps, hd)`` is.  So the writer reads each page a row touches,
+    merges the row's tokens into it, and writes it back whole."""
+    Pd, n_pages, Hkv, ps, hd = pages.shape
+    T = page.shape[-1]
+    page, off = page.reshape(-1, T), off.reshape(-1, T)
+    # head-major like the pool, so the merged pages come out in its order
+    kv = kv.reshape(Pd, -1, T, Hkv, hd).astype(pages.dtype).swapaxes(2, 3)
+    R = page.shape[0]
+    n_tiles = (T + ps - 2) // ps + 1        # pages T consecutive tokens span
+    # the token each slot of the row's pages receives (outside [0, T): none)
+    tok = jnp.arange(n_tiles * ps, dtype=jnp.int32)[None] - off[:, :1]
+    first = jnp.clip(tok[:, ::ps], 0, T - 1)
+    tile = jnp.where(tok[:, ::ps] < T,
+                     jnp.take_along_axis(page, first, axis=1), n_pages - 1)
+    tok_c = jnp.clip(tok, 0, T - 1)
+    mine = (tok >= 0) & (tok < T) & (
+        jnp.take_along_axis(page, tok_c, axis=1) == jnp.repeat(tile, ps, 1))
+    # slide each row's tokens to their page slots: pad, then one slice a row
+    kv = jnp.pad(kv, ((0, 0), (0, 0), (0, 0), (ps, n_tiles * ps - T), (0, 0)))
+    new = jax.vmap(lambda x, o: jax.lax.dynamic_slice_in_dim(
+        x, ps - o, n_tiles * ps, axis=2), in_axes=(1, 0), out_axes=1)(
+            kv, off[:, 0])
+    new = new.reshape(Pd, R, Hkv, n_tiles, ps, hd).swapaxes(2, 3)
+    mine = mine.reshape(1, R, n_tiles, 1, ps, 1)
+    return pages.at[:, tile].set(jnp.where(mine, new, pages[:, tile]))
 
 
 def gather_pages(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
