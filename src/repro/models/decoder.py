@@ -98,6 +98,19 @@ def _sinusoid_at(positions, d_model: int):
 # prefill (ISO lives here)
 # ---------------------------------------------------------------------------
 
+def prefill_chunk_lengths(S: int, iso: ISOConfig, cfg: ModelConfig,
+                          tp: int) -> Tuple[int, ...]:
+    """ISO chunk lengths of a prefill call over ``S`` positions."""
+    if cfg.residual_wiring == "ladder":
+        # ladder wiring supplies the overlap itself (stage k-1's reduce
+        # hides behind stage k's compute); an ISO chunk interleave would
+        # resolve each chunk's pending during the OTHER chunk's unit and
+        # silently restore the standard wiring per chunk — single-chunk
+        # schedule, always, so chunked/resumed grants stay function-equal
+        return (S,)
+    return split_chunks(S, iso, cfg, tp=tp)
+
+
 def prefill(params, cfg: ModelConfig, ctx: AxisCtx, iso: ISOConfig, *,
             tokens=None, embeds=None, extra_embeds=None,
             logits_mode: str = "all", return_cache: bool = False,
@@ -145,15 +158,8 @@ def prefill(params, cfg: ModelConfig, ctx: AxisCtx, iso: ISOConfig, *,
         embeds = jnp.concatenate([extra_embeds.astype(embeds.dtype), embeds], axis=1)
     B, S, D = embeds.shape
 
-    lengths = split_chunks(S, iso, cfg, tp=ctx.tp)
+    lengths = prefill_chunk_lengths(S, iso, cfg, ctx.tp)
     ladder = cfg.residual_wiring == "ladder"
-    if ladder:
-        # ladder wiring supplies the overlap itself (stage k-1's reduce
-        # hides behind stage k's compute); an ISO chunk interleave would
-        # resolve each chunk's pending during the OTHER chunk's unit and
-        # silently restore the standard wiring per chunk — single-chunk
-        # schedule, always, so chunked/resumed grants stay function-equal
-        lengths = [S]
     starts, acc = [], 0
     for l in lengths:
         starts.append(acc)

@@ -58,7 +58,8 @@ from repro.obs.registry import (ACCEPT_LEN_BUCKETS, GRANT_SIZE_BUCKETS,
                                 MetricsRegistry, TPOT_BUCKETS_S,
                                 TTFT_BUCKETS_S)
 from repro.obs.trace import TraceRing
-from repro.models.decoder import cache_specs, decoder_param_specs
+from repro.models.decoder import (cache_specs, decoder_param_specs,
+                                  prefill_chunk_lengths)
 from repro.serving.kvcache import (OutOfPages, PrefixCache, pages_for,
                                    scatter_pages, token_page_coords,
                                    window_page_coords)
@@ -248,9 +249,10 @@ class PagedEngine:
         # *_dispatch_s pair keeps the async (dispatch-only) view
         self.registry.counters((
             "prefill_s", "decode_s", "prefill_dispatch_s",
-            "decode_dispatch_s", "prefill_tokens", "decode_tokens",
-            "completed", "decode_calls", "prefill_calls", "steps",
-            "preemptions", "ttft_sum", "ttft_n", "prefix_shared_tokens",
+            "decode_dispatch_s", "prefill_tokens", "prefill_iso_tokens",
+            "decode_tokens", "completed", "decode_calls", "prefill_calls",
+            "steps", "preemptions", "ttft_sum", "ttft_n",
+            "prefix_shared_tokens",
             "cow_copies", "peak_used_pages", "prefill_pad_tokens",
             "prefill_samples", "spec_calls", "spec_tokens", "prefill_grants",
             "resumed_grants", "prefill_pad_rows", "migrations",
@@ -797,6 +799,15 @@ class PagedEngine:
         with self.trace.span("engine/logits", self._host["host_logits_s"]):
             return np.asarray(jax.device_get(logits))
 
+    def _count_prefill_tokens(self, T: int, n_real: int) -> None:
+        """Real prompt tokens of one prefill call over ``T`` positions; those
+        of a call that runs in two or more ISO chunks count again in
+        ``prefill_iso_tokens``."""
+        self.metrics["prefill_tokens"] += n_real
+        if len(prefill_chunk_lengths(T, self.config.iso, self.cfg,
+                                     self.tp)) > 1:
+            self.metrics["prefill_iso_tokens"] += n_real
+
     def _run_grant(self, st: RequestState, start: int, n_tokens: int,
                    padded: int, last: bool) -> Optional[int]:
         """Execute one prefill grant; returns the sampled token if ``last``.
@@ -831,7 +842,7 @@ class PagedEngine:
         if st.t_first_grant < 0:
             st.t_first_grant = t0_wall
         self.metrics["prefill_s"] += dur
-        self.metrics["prefill_tokens"] += n_tokens
+        self._count_prefill_tokens(padded, n_tokens)
         self.metrics["prefill_pad_tokens"] += padded - n_tokens
         self.metrics["prefill_calls"] += 1
         self.trace.emit("prefill_call", rid=req.rid, slot=slot, dur=dur,
@@ -927,7 +938,7 @@ class PagedEngine:
                 st.t_first_grant = t0_wall
         n_total = int(n_reals.sum())
         self.metrics["prefill_s"] += dur
-        self.metrics["prefill_tokens"] += n_total
+        self._count_prefill_tokens(T, n_total)
         self.metrics["prefill_pad_tokens"] += rows * T - n_total
         self.metrics["prefill_pad_rows"] += rows - R
         self.metrics["prefill_calls"] += 1

@@ -6,7 +6,9 @@ kernel is in the program as a TPU custom call.  Widths are qwen3-4b's: head_dim
 128, bf16 pools, 8 KV heads on one chip and 2 (its TP=4 share) per chip.
 
 The engine's own decode and prefill programs are compiled here too, to check
-that the page writer leaves the KV pool in its own layout.
+that the page writer leaves the KV pool in its own layout, and at Qwen3-8B's
+widths at tensor parallel 4 over a described 2x2 host, to count the
+all-reduces the ISO schedule puts in each program.
 
 All chip compiles of the test suite live in this one file.  The topology is
 described inside a module-scoped fixture, never at import, so that every test
@@ -19,7 +21,8 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.config import (Config, ISOConfig, ModelConfig, ParallelConfig,
                           RuntimeConfig, ServingConfig)
@@ -27,20 +30,27 @@ from repro.kernels import backend
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.flash_prefill_paged import flash_prefill_paged
 from repro.models import api
+from repro.models.decoder import decoder_param_specs, prefill_chunk_lengths
 from repro.serving import PagedEngine
+from repro.serving.kvstate import KVPool
+from repro.serving.paged_engine import KV_POOL_SPEC
 
 HD, GROUP, B, MB, N = 128, 4, 4, 64, 257
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -160,3 +170,99 @@ def test_engine_programs_keep_pool_layout(one_chip, chat_engine, program,
     assert any(shape == stacked for shape, _ in seen), "no pool in the text"
     wrong = {k: n for k, n in seen.items() if k[1] != own[k[0]]}
     assert not wrong, f"pool-sized arrays relayouted: {wrong}"
+
+
+# ---------------------------------------------------------------------------
+# tensor parallel 4: the all-reduces of the ISO schedule
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tp4_engine(topo):
+    """A PagedEngine at Qwen3-8B widths with 2 layers, sharded over a (1, 4)
+    mesh of the described 2x2 host, with the docs cell's page size, slots
+    and row length (516 table pages).  Its own pool is a few pages on the
+    CPU (a described chip holds no array); the programs are lowered for the
+    cell's 5,400 pages, placed as the engine places them."""
+    mesh = jax.make_mesh((1, 4), ("data", "model"), devices=topo.devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    cfg = ModelConfig(name="qwen3-8b-2l", family="dense", num_layers=2,
+                      d_model=4096, num_heads=32, num_kv_heads=8,
+                      head_dim=128, d_ff=12288, vocab_size=151936,
+                      qk_norm=True, tie_embeddings=False)
+    config = Config(
+        model=cfg, parallel=ParallelConfig(data=1, model=4),
+        iso=ISOConfig(enabled=True, num_chunks=2, min_chunk_tokens=256,
+                      chunk_align=128),
+        runtime=RuntimeConfig(mode="serve"),
+        serving=ServingConfig(page_size=16, max_batch=24, max_len=8256,
+                              num_pages=5400, prefill_token_budget=2048))
+    params = jax.eval_shape(
+        lambda: api.init_params(jax.random.PRNGKey(0), cfg, 4))
+    pool = KVPool.create(cfg, 8, 16, tp=4)
+    return PagedEngine(config, params, mesh=mesh, kv_pool=pool)
+
+
+def _tp4_program_text(eng, program):
+    mesh = eng.mesh
+    rep = lambda shape, dt: jax.ShapeDtypeStruct(          # noqa: E731
+        shape, dt, sharding=NamedSharding(mesh, P()))
+    params = jax.tree_util.tree_map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        eng.params, decoder_param_specs(eng.params),
+        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    k = eng.kv.arrays["k"][0]
+    pool = jax.ShapeDtypeStruct((k.shape[0], 5401) + k.shape[2:], k.dtype,
+                                sharding=NamedSharding(mesh, KV_POOL_SPEC))
+    kv = {"k": (pool,) * len(eng.kv.arrays["k"]),
+          "v": (pool,) * len(eng.kv.arrays["v"]),
+          "pos": rep((5401, eng.ps), jnp.int32)}
+    B, MB = eng.max_batch, eng.max_blocks
+    kind, a, b = program
+    if kind == "decode":                 # (K, S)
+        fn = eng._get_decode(a, b)
+        states = jax.tree_util.tree_map(lambda x: rep(x.shape, x.dtype),
+                                        eng.states)
+        args = (params, rep((B, a), jnp.int32), rep((B, MB), jnp.int32),
+                rep((B,), jnp.int32), kv, states, rep((B,), jnp.bool_))
+    else:                                # (T, all_fresh), one row
+        fn = eng._get_prefill_batched(a, 1, b)
+        args = (params, rep((1, a), jnp.int32), kv, rep((1, MB), jnp.int32),
+                rep((1,), jnp.int32), rep((1,), jnp.int32))
+    return fn.lower(*args).compile().as_text()
+
+
+def _all_reduces(text):
+    """All-reduce ops per computation of compiled HLO text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.-]+) ", line)
+        if m and not line.startswith(" "):
+            name = m.group(2)
+        if re.search(r" all-reduce(-start)?\(", line):
+            out[name] = out.get(name, 0) + 1
+    return out
+
+
+@pytest.mark.parametrize("program,stages", [
+    (("prefill", 512, True), 2), (("prefill", 512, False), 2),
+    (("decode", 1, 1), 2)], ids=["prefill_fresh", "prefill_resumed",
+                                 "decode_batch_split"])
+def test_tp4_programs_hold_the_iso_all_reduces(tp4_engine, program, stages,
+                                               monkeypatch):
+    """The layer loop's body holds one all-reduce per reducing stage
+    (attention, MLP) per ISO unit: per chunk in prefill (a 512-token call
+    runs in two chunks), per half of the batch in batch-split decode.
+    Outside the loop: the vocabulary-sharded embedding's reduce and the
+    trailing reduce of the last unit."""
+    monkeypatch.setattr(backend, "default_interpret", lambda: False)
+    eng = tp4_engine
+    assert eng._decode_schedule == "batch_split"
+    if program[0] == "prefill":
+        units = len(prefill_chunk_lengths(program[1], eng.config.iso,
+                                          eng.cfg, eng.tp))
+        assert units == 2
+    else:
+        units = 2
+    counts = _all_reduces(_tp4_program_text(eng, program))
+    assert sorted(counts.values()) == [2, stages * units], counts
